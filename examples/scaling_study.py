@@ -87,4 +87,4 @@ print(f"bert-mlm-120m(reduced) b={B} seq={S}: "
       f"step_ema={t['step_time_ema']*1e3:.1f}ms "
       f"tokens/s={t['tokens_per_s']:.0f} "
       f"host_stall={t['stall_fraction']*100:.1f}% "
-      f"mfu(v5e-peak)={mlog.mfu[-1]:.2e} compiles={t['n_traces']:.0f}")
+      f"mfu={mlog.mfu[-1]:.2e} compiles={t['n_traces']:.0f}")

@@ -23,6 +23,8 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from repro.core.scaling import TPU_V5E
+
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
@@ -117,7 +119,7 @@ class Roofline:
     # raw XLA numbers (while bodies counted once — reference only)
     xla_cost: Dict[str, float] = field(default_factory=dict)
     # hardware
-    peak_flops: float = 197e12
+    peak_flops: float = TPU_V5E.peak_flops
     hbm_bw: float = 819e9
     link_bw: float = 50e9
     hbm_cap: float = 16e9
@@ -182,15 +184,6 @@ class Roofline:
         return d
 
 
-def xla_cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jax versions: older
-    releases return a one-element list of dicts, newer ones a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
             sharding: str, model_flops_global: float,
             hlo_text: Optional[str] = None, pallas_cost=None) -> Roofline:
@@ -203,7 +196,7 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
     """
     from repro.analysis.hlocost import analyze_text
 
-    ca = xla_cost_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     txt = hlo_text if hlo_text is not None else compiled.as_text()
     cost = analyze_text(txt, pallas_cost)

@@ -39,7 +39,27 @@ class Chip:
     net_bw: float              # bytes/s inter-node (DCN / 25GbE)
 
 
-TPU_V5E = Chip("tpu-v5e", 197e12, 16e9, 819e9, 50e9, 25e9)
+# Peak dense bf16 FLOP/s of one chip, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite": 197 TFLOP/s, Google Cloud documentation, "TPU v5e".
+PEAK_FLOPS: Dict[str, float] = {"TPU v5 lite": 197e12}
+
+
+def device_peak_flops(device) -> float:
+    """Peak bf16 FLOP/s of ``device`` for MFU: NaN on the CPU, which has
+    no meaningful MFU; an accelerator whose ``device_kind`` is not in
+    :data:`PEAK_FLOPS` raises rather than borrowing another chip's peak."""
+    if device.platform == "cpu":
+        return float("nan")
+    try:
+        return PEAK_FLOPS[device.device_kind]
+    except KeyError:
+        raise KeyError(f"no peak FLOP/s on record for device kind "
+                       f"{device.device_kind!r}; add it to PEAK_FLOPS "
+                       "with its published source") from None
+
+
+TPU_V5E = Chip("tpu-v5e", PEAK_FLOPS["TPU v5 lite"], 16e9, 819e9, 50e9,
+               25e9)
 H100_NVL = Chip("h100-nvl", 835e12, 94e9, 3.9e12, 300e9, 25e9 / 8)  # 25 GbE
 
 

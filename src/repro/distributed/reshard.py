@@ -270,14 +270,7 @@ def target_regions(sharding, global_shape: Tuple[int, ...]) -> List[Region]:
     collapse to one region).  These are exactly the byte ranges a
     resharding restore must read."""
     global_shape = tuple(global_shape)
-    try:
-        imap = sharding.addressable_devices_indices_map(global_shape)
-    except AttributeError:  # older jax: filter the global map by process
-        import jax
-        imap = {d: idx
-                for d, idx in
-                sharding.devices_indices_map(global_shape).items()
-                if d.process_index == jax.process_index()}
+    imap = sharding.addressable_devices_indices_map(global_shape)
     regions: List[Region] = []
     seen = set()
     for idx in imap.values():

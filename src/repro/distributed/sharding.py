@@ -53,7 +53,7 @@ __all__ = [
     "GRAD_SYNC_EP", "GRAD_SYNC_TP", "GRAD_SYNC_XLA", "GRAD_SYNC_NONE",
     "RULES", "TP_LEAF_AXES", "tp_compatible",
     "spec_for", "tree_shardings", "batch_axes", "batch_spec",
-    "activation_sharding", "shard_map", "optimization_barrier",
+    "activation_sharding",
     "local_batch_size", "process_batch_slice",
     "flash_attn_ctx", "flash_shard_shapes", "flash_analytic_cost",
     "ssd_analytic_cost", "attn_shard_ctx",
@@ -61,46 +61,6 @@ __all__ = [
 ]
 
 Candidate = Union[str, Tuple[str, ...]]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: ``jax.shard_map`` (>= 0.6, with
-    ``check_vma``) or ``jax.experimental.shard_map.shard_map`` (0.4.x,
-    where the same knob is spelled ``check_rep``)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as fn
-
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
-
-# ---------------------------------------------------------------------------
-# Differentiable optimization barrier
-# ---------------------------------------------------------------------------
-#
-# jax.lax.optimization_barrier has no JVP rule on jax 0.4.37, so any train
-# step that pins values with it (attention pins q/k/v dtypes before the k/v
-# all-gathers) cannot be differentiated.  The barrier is semantically the
-# identity, so a custom_jvp passthrough is exact: the primal keeps the
-# barrier (preserving the scheduling constraint), the tangent passes
-# through untouched (reverse mode transposes the identity).
-
-
-@jax.custom_jvp
-def optimization_barrier(operands):
-    """Differentiable ``jax.lax.optimization_barrier``: identity with a
-    custom_jvp passthrough (see the block comment above), so train steps
-    that pin scheduling with it stay reverse-differentiable."""
-    return jax.lax.optimization_barrier(operands)
-
-
-@optimization_barrier.defjvp
-def _optimization_barrier_jvp(primals, tangents):
-    (operands,), (dots,) = primals, tangents
-    return optimization_barrier(operands), dots
 
 
 # ---------------------------------------------------------------------------
@@ -318,7 +278,7 @@ def flash_attn_ctx(cfg, mesh: Mesh, mode: str, global_batch: int,
                 return kops.flash_attention(ql, kl_, vl_, causal, window,
                                             softcap, scale)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
             out_specs=qspec, check_vma=False)(q, k, v)
 

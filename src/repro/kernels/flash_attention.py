@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -2.0e38
 DEFAULT_BQ = 512  # (bq, D) + (bk, D) + (bq, bk) f32 tiles fit 16MB VMEM
 DEFAULT_BK = 512
@@ -86,7 +88,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None, softcap: float = 0.0,
                         scale: Optional[float] = None,
                         block_q: int = DEFAULT_BQ, block_k: int = DEFAULT_BK,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """q:(B,S,H,D), k/v:(B,S,Hkv,D) -> (B,S,H,D)."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -119,6 +121,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -2,9 +2,10 @@
 
 Each op is differentiable: forward runs the Pallas kernel, backward is the
 ``jax.vjp`` of the pure-jnp oracle (recompute — matches the usual flash
-backward strategy of not storing the score matrix).  On this CPU container
-kernels execute in interpret mode; on TPU ``interpret=False`` compiles the
-real kernels.  ``PALLAS_INTERPRET`` may be flipped by the launcher.
+backward strategy of not storing the score matrix).  Whether a kernel
+compiles or runs in the interpreter follows the default backend
+(:func:`repro.kernels.resolve_interpret`): interpreted on the CPU,
+compiled on the TPU.
 """
 from __future__ import annotations
 
@@ -20,8 +21,6 @@ from repro.kernels.fused_xent import fused_xent as _fused_xent
 from repro.kernels.paged_attention import paged_attention_fwd
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
-PALLAS_INTERPRET = True  # CPU container; launcher sets False on real TPU
-
 
 # ---------------------------------------------------------------------------
 # flash attention
@@ -33,8 +32,7 @@ def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None, softcap: float = 0.0,
                     scale: Optional[float] = None):
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               softcap=softcap, scale=scale,
-                               interpret=PALLAS_INTERPRET)
+                               softcap=softcap, scale=scale)
 
 
 def _fa_fwd(q, k, v, causal, window, softcap, scale):
@@ -66,8 +64,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     through it).  q:(B,H,D) against (NP,P,Hkv,D) pools via (B,maxp)
     block tables; see ``kernels/paged_attention.py``."""
     return paged_attention_fwd(q, k_pages, v_pages, block_tables, seq_lens,
-                               window=window, softcap=softcap, scale=scale,
-                               interpret=PALLAS_INTERPRET)
+                               window=window, softcap=softcap, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -77,7 +74,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def ssd(x, dt, A, B, C, chunk: int = 256):
-    return _ssd_scan(x, dt, A, B, C, chunk, interpret=PALLAS_INTERPRET)
+    return _ssd_scan(x, dt, A, B, C, chunk)
 
 
 def _ssd_fwd(x, dt, A, B, C, chunk):
@@ -101,7 +98,7 @@ ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 @jax.custom_vjp
 def xent(logits, labels):
-    return _fused_xent(logits, labels, interpret=PALLAS_INTERPRET)
+    return _fused_xent(logits, labels)
 
 
 def _xe_fwd(logits, labels):

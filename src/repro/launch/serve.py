@@ -43,9 +43,12 @@ def main():
     from repro.configs import default_run_config, get_config, \
         reduced as reduce_cfg
     from repro.configs.base import ShapeConfig
+    from repro.launch.compile_cache import init_compile_cache
     from repro.models import build_model
     from repro.observability import MetricsRegistry, Tracer, set_tracer
     from repro.serve.engine import PagedServeEngine, ServeEngine
+
+    init_compile_cache()
 
     tracer = None
     if args.trace_dir:

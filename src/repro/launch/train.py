@@ -61,7 +61,9 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def main():
+def main(argv=None):
+    """Run the launcher on ``argv`` (``sys.argv[1:]`` when None) and
+    return the final ``(state, TrainerLog)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bert-mlm-120m")
     ap.add_argument("--steps", type=int, default=100)
@@ -145,6 +147,9 @@ def main():
                     help="gradient collective bucket size (MB); one "
                          "psum (ddp) or psum_scatter+all_gather (fsdp) "
                          "per bucket, overlapped with compute")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="build the mesh over the first N devices of a "
+                         "single-process run (0 = all)")
     ap.add_argument("--process-index", type=int, default=None)
     ap.add_argument("--process-count", type=int, default=None)
     ap.add_argument("--log-every", type=int, default=10)
@@ -163,7 +168,7 @@ def main():
     ap.add_argument("--straggler-ratio", type=float, default=2.0,
                     help="straggler threshold as a multiple of the "
                          "cross-rank median phase time")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from repro.configs import default_run_config, get_config, \
         reduced as reduce_cfg
@@ -171,11 +176,13 @@ def main():
     from repro.core.mlm import mask_tokens
     from repro.data import DataPipeline, NetworkFS
     from repro.distributed import maybe_initialize_distributed
+    from repro.launch.compile_cache import init_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models import build_model
     from repro.train.optimizer import AdamWConfig
     from repro.train.runner import StepRunner, TrainLoop, resume
 
+    init_compile_cache()
     # multi-controller wiring (env-keyed; single-process no-op) — must run
     # before the first jax device/process query below
     if maybe_initialize_distributed():
@@ -265,6 +272,11 @@ def main():
     # gradient-sync strategy (bucketed overlapped psum for multi-shard
     # ddp; the staged pipeline when --pipeline-stages carves a pipe axis)
     n_dev = jax.device_count()
+    if args.devices:
+        if not 0 < args.devices <= n_dev or jax.process_count() > 1:
+            ap.error(f"--devices {args.devices} needs a single-process run "
+                     f"with at least that many of its {n_dev} devices")
+        n_dev = args.devices
     carvers = [n for n, v in (("--pipeline-stages", args.pipeline_stages),
                               ("--expert-parallel", args.expert_parallel),
                               ("--tensor-parallel", args.tensor_parallel))
@@ -447,6 +459,7 @@ def main():
         print(f"[trace] wrote {path} ({len(tracer)} events, "
               f"{tracer.dropped} dropped) — open in ui.perfetto.dev")
     print("[done]")
+    return state, log
 
 
 if __name__ == "__main__":

@@ -242,11 +242,7 @@ def apply_attn(p, h, cfg: ModelConfig, spec: LayerSpec, *, positions,
         elif shard_ctx is not None and "q" in shard_ctx:
             # pin dtypes before the k/v all-gathers: without the barrier XLA
             # sinks the f32->bf16 convert past the gather, doubling traffic
-            # (differentiable wrapper: lax.optimization_barrier has no JVP
-            # rule on this jax version)
-            from repro.distributed.sharding import optimization_barrier
-
-            q, k, v = optimization_barrier((q, k, v))
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
             q = shard_ctx["q"](q)
             k = shard_ctx["kv"](k)
             v = shard_ctx["kv"](v)

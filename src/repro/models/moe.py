@@ -199,7 +199,7 @@ def apply_moe_ep(p, x, cfg: ModelConfig, mesh, *, batch_axes, expert_axis):
     for k in ("wi", "wg", "wo"):
         wspec[k] = P(expert_axis)
 
-    from repro.distributed.sharding import shard_map
+    from jax import shard_map
 
     @functools.partial(
         shard_map,

@@ -47,7 +47,7 @@ def dist_decode_attend(q, k_new, v_new, cache, pos, cfg, dist):
     scale = cfg.query_scale if cfg.query_scale else q.shape[-1] ** -0.5
     cap = cfg.attn_logit_softcap
 
-    from repro.distributed.sharding import shard_map
+    from jax import shard_map
 
     @functools.partial(
         shard_map, mesh=mesh,

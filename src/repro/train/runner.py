@@ -40,10 +40,7 @@ from repro.train.train_step import (batch_shardings, init_state,
                                     make_train_step, state_shardings)
 
 __all__ = ["StepRunner", "TrainLoop", "TrainerLog", "AsyncMetrics",
-           "resume", "resume_resharded", "DEFAULT_PEAK_FLOPS"]
-
-# TPU v5e peak (matches analysis.roofline defaults); override per hardware
-DEFAULT_PEAK_FLOPS = 197e12
+           "resume", "resume_resharded"]
 
 
 # ---------------------------------------------------------------------------
@@ -412,7 +409,7 @@ class StepRunner:
         return model_flops(self.model.cfg, tokens_per_step) / n_dev
 
     def mfu(self, step_time_s: float, tokens_per_step: int,
-            peak_flops: float = DEFAULT_PEAK_FLOPS) -> float:
+            peak_flops: float) -> float:
         if step_time_s <= 0:
             return float("nan")
         return self.flops_per_step(tokens_per_step) / (
@@ -467,7 +464,7 @@ class TrainLoop:
                  prefetch_size: int = 2, aot_compile: bool = True,
                  metrics_lag: int = 8,
                  journal=None, max_rollbacks: int = 2,
-                 peak_flops: float = DEFAULT_PEAK_FLOPS,
+                 peak_flops: Optional[float] = None,
                  tracer=None, metrics=None,
                  metrics_jsonl: Optional[str] = None,
                  straggler_every: int = 0,
@@ -519,6 +516,15 @@ class TrainLoop:
         self.metrics_lag = metrics_lag
         self.journal = journal
         self.max_rollbacks = max_rollbacks
+        if peak_flops is None:
+            from repro.core.scaling import device_peak_flops
+
+            # resolved up front: an accelerator with no peak on record
+            # fails before training, not at the first log line
+            mesh = runner.mesh
+            peak_flops = device_peak_flops(
+                mesh.devices.flat[0] if mesh is not None
+                else jax.devices()[0])
         self.peak_flops = peak_flops
         self.tracer = tracer
         self.metrics = metrics

@@ -161,11 +161,10 @@ def build_attn_ctx(cfg, mesh, run: RunConfig, global_batch: int,
                                    seq_len)
         if flash is not None:
             ctx["flash"] = flash
-    if "flash" not in ctx and jax.default_backend() != "cpu":
-        # context-parallel q/score sharding is a TPU perf feature; on the
-        # CPU backend (virtual-device tests) the XLA SPMD partitioner
-        # segfaults partitioning the seq-sharded q pattern (jax 0.4.37),
-        # and CP buys nothing on a host CPU anyway
+    if "flash" not in ctx:
+        # context-parallel q/score sharding (tp modes whose kv heads do
+        # not divide the model axis); tests/test_multidevice.py checks it
+        # against one device on CPU virtual devices
         cp = shd.attn_shard_ctx(cfg, mesh, run.sharding, global_batch,
                                 seq_len)
         if cp is not None:
@@ -327,7 +326,7 @@ def make_grad_fn(model: Model, run: RunConfig,
             # declared-replicated output must be the global value
             return jax.lax.psum(loss, axis), grads, metrics
 
-        return shd.shard_map(
+        return jax.shard_map(
             body, mesh=plan.mesh,
             in_specs=(P(), _dp_batch_spec(plan)),
             out_specs=(P(), P(), P()), check_vma=False)
@@ -343,7 +342,7 @@ def make_grad_fn(model: Model, run: RunConfig,
         # grads come out as shards; the P(dp)-on-shard-dim out specs
         # reassemble them into the full summed gradient tree, so callers
         # compare against the fused reference leaf-for-leaf
-        return shd.shard_map(
+        return jax.shard_map(
             scatter_body, mesh=plan.mesh,
             in_specs=(pspecs, _dp_batch_spec(plan)),
             out_specs=(P(), pspecs, P()), check_vma=False)
@@ -361,7 +360,7 @@ def make_grad_fn(model: Model, run: RunConfig,
         # P('expert')-on-experts out specs reassemble the full expert
         # gradient tree, so callers compare against the dense one-hot
         # oracle leaf-for-leaf
-        return shd.shard_map(
+        return jax.shard_map(
             ep_body, mesh=plan.mesh,
             in_specs=(pspecs, _dp_batch_spec(plan)),
             out_specs=(P(), pspecs, P()), check_vma=False)
@@ -380,7 +379,7 @@ def make_grad_fn(model: Model, run: RunConfig,
         # P('model')/P(data)-on-shard-dim out specs reassemble the full
         # summed gradient tree, so callers compare against the fused
         # reference leaf-for-leaf
-        return shd.shard_map(
+        return jax.shard_map(
             tp_body, mesh=plan.mesh,
             in_specs=(pspecs, _dp_batch_spec(plan)),
             out_specs=(P(), pspecs, P()), check_vma=False)
@@ -392,7 +391,7 @@ def make_grad_fn(model: Model, run: RunConfig,
         # grads come out stage-local; the P('pipe')-on-layers out specs
         # restack them into the full depth-L gradient tree, so callers
         # compare against the unpipelined reference leaf-for-leaf
-        return shd.shard_map(
+        return jax.shard_map(
             accum, mesh=plan.mesh,
             in_specs=(pspecs, _dp_batch_spec(plan)),
             out_specs=(P(), pspecs, P()), check_vma=False)
@@ -464,7 +463,7 @@ def _make_overlap_ddp_step(model: Model, run: RunConfig, opt: AdamWConfig,
         metrics = {**metrics, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, metrics
 
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh, in_specs=(P(), _dp_batch_spec(plan)),
         out_specs=(P(), P()), check_vma=False)
 
@@ -591,7 +590,7 @@ def _make_scatter_fsdp_step(model: Model, run: RunConfig, opt: AdamWConfig,
         metrics = {**metrics, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, metrics
 
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(state_spec, _dp_batch_spec(plan)),
         out_specs=(state_spec, P()), check_vma=False)
@@ -664,7 +663,7 @@ def _make_ep_step(model: Model, run: RunConfig, opt: AdamWConfig,
         metrics = {**metrics, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, metrics
 
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(state_spec, _dp_batch_spec(plan)),
         out_specs=(state_spec, P()), check_vma=False)
@@ -825,7 +824,7 @@ def _make_tp_step(model: Model, run: RunConfig, opt: AdamWConfig,
         metrics = {**metrics, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, metrics
 
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(state_spec, _dp_batch_spec(plan)),
         out_specs=(state_spec, P()), check_vma=False)
@@ -938,7 +937,7 @@ def _make_pipeline_step(model: Model, run: RunConfig, opt: AdamWConfig,
         metrics = {**metrics, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, metrics
 
-    return shd.shard_map(
+    return jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(state_spec, _dp_batch_spec(plan)),
         out_specs=(state_spec, P()), check_vma=False)

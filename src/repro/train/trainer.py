@@ -42,13 +42,12 @@ def train(model: Model, run: RunConfig, opt: AdamWConfig,
         import jax.numpy as jnp
 
         state = jax.tree_util.tree_map(jnp.array, state)
-    kw = {} if peak_flops is None else {"peak_flops": peak_flops}
     loop = TrainLoop(runner, log_every=log_every, ckpt_path=ckpt_path,
                      ckpt_every=ckpt_every, ckpt_dir=ckpt_dir,
                      process_index=process_index,
                      process_count=process_count,
                      async_checkpoint=async_checkpoint,
                      device_prefetch=device_prefetch, aot_compile=aot_compile,
-                     **kw)
+                     peak_flops=peak_flops)
     return loop.run(data, steps, state=state, seed=seed,
                     start_step=start_step)

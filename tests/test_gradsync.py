@@ -83,7 +83,7 @@ def test_bucketed_psum_roundtrip_preserves_structure():
     # concat/slice/reshape round-trip without needing multiple devices
     from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import shard_map
+    from jax import shard_map
 
     mesh = make_host_mesh(1, 1)
     tree = {"a": jnp.arange(12.0).reshape(3, 4),
@@ -103,7 +103,7 @@ def test_bucketed_psum_roundtrip_preserves_structure():
 def test_fused_psum_is_single_bucket_and_matches_bucketed():
     from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import shard_map
+    from jax import shard_map
 
     mesh = make_host_mesh(1, 1)
     tree = {"a": jnp.arange(6.0).reshape(2, 3), "b": jnp.ones((4,))}
@@ -487,7 +487,7 @@ def test_fsdp_gather_scatter_roundtrip_on_one_device_mesh():
     # blocks<->leaf reshape round-trip for dim0 AND non-dim0 shard dims
     from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.sharding import shard_map
+    from jax import shard_map
 
     mesh = make_host_mesh(1, 1)
     tree = {"a": jnp.arange(24.0).reshape(6, 4),
@@ -710,7 +710,12 @@ def test_scatter_fsdp_matches_fused_on_two_device_mesh():
                 assert len(sp.psum) >= 1, 'odd vocab: psum remainder'
                 _, gs, ms = jax.jit(make_grad_fn(model, run, mesh,
                                                  plan))(params, batch)
-                close(gref, gs)                           # rtol 1e-6
+                # fused and scatter sum the B*S per-token terms in
+                # different orders (and XLA:CPU's threaded reductions
+                # vary from run to run): f32 agreement is bounded near
+                # sqrt(B*S)*eps ~ 2e-6 at leaf scale, so 1e-5 as for the
+                # grad norm below
+                close(gref, gs, rtol=1e-5)
                 np.testing.assert_allclose(float(mref['loss']),
                                            float(ms['loss']), rtol=1e-6)
 

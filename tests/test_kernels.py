@@ -168,3 +168,18 @@ def test_xent_grad_matches_softmax_identity():
     g = jax.grad(lambda l: ops.xent(l, labels).sum())(logits)
     want = jax.nn.softmax(logits, -1) - jax.nn.one_hot(labels, 64)
     np.testing.assert_allclose(g, want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("backend,explicit,want", [
+    ("cpu", None, True), ("tpu", None, False), ("gpu", None, RuntimeError),
+    ("tpu", True, True), ("cpu", False, False)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, explicit,
+                                        want):
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError):
+            resolve_interpret(explicit)
+    else:
+        assert resolve_interpret(explicit) is want

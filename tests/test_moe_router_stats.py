@@ -30,7 +30,7 @@ def test_psum_router_stats_reproduce_global_aux(n_experts, top_k):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_config, reduced
-        from repro.distributed.sharding import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_host_mesh
         from repro.models.moe import route
 
@@ -76,7 +76,7 @@ def test_psum_router_stats_grads_sum_to_global():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_config, reduced
-        from repro.distributed.sharding import shard_map
+        from jax import shard_map
         from repro.launch.mesh import make_host_mesh
         from repro.models.moe import route
 

@@ -60,6 +60,54 @@ def test_fsdp_tp_train_step_matches_single_device():
     """))
 
 
+def test_context_parallel_attention_matches_single_device():
+    # starcoder2's 2 kv heads do not divide a 4-wide model axis, so tp
+    # falls back and attention shards q and the scores over the sequence
+    print(_run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.configs import get_config, reduced
+        from repro.configs.base import RunConfig, ShapeConfig
+        from repro.models import build_model
+        from repro.train.optimizer import AdamWConfig
+        from repro.train.train_step import (build_attn_ctx, init_state,
+                                            make_train_step,
+                                            state_shardings)
+        from repro.launch.mesh import make_host_mesh
+
+        cfg = reduced(get_config('starcoder2-3b'), d_model=128)
+        model = build_model(cfg)
+        shape = ShapeConfig('t', 64, 4, 'train')
+        opt = AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=10)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0,
+                                  cfg.vocab_size)
+        batch = {'tokens': toks, 'labels': jnp.roll(toks, -1, 1),
+                 'loss_mask': jnp.ones((4, 64), jnp.float32)}
+        run1 = RunConfig(model=cfg, shape=shape, sharding='ddp',
+                         param_dtype='float32', activation_dtype='float32')
+        state = init_state(model, jax.random.PRNGKey(0), run1)
+        s1, m1 = jax.jit(make_train_step(model, run1, opt))(state, batch)
+
+        mesh = make_host_mesh(2, 4)
+        run2 = run1.with_(sharding='fsdp_tp')
+        assert sorted(build_attn_ctx(cfg, mesh, run2, 4, 64)) == \
+            ['kv', 'q']
+        st_sh = state_shardings(model, mesh, run2)
+        state2 = jax.device_put(
+            init_state(model, jax.random.PRNGKey(0), run2), st_sh)
+        step2 = jax.jit(make_train_step(model, run2, opt, mesh),
+                        in_shardings=(st_sh, None),
+                        out_shardings=(st_sh, None))
+        s2, m2 = step2(state2, batch)
+        np.testing.assert_allclose(float(m1['loss']), float(m2['loss']),
+                                   rtol=2e-4)
+        for a, b in zip(jax.tree_util.tree_leaves(s1['params']),
+                        jax.tree_util.tree_leaves(s2['params'])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-3)
+        print('context-parallel == single-device OK')
+    """))
+
+
 @pytest.mark.slow
 def test_moe_ep_matches_dense_on_mesh():
     print(_run("""

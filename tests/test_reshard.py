@@ -29,6 +29,7 @@ Three layers, cheapest first:
 """
 import json
 import os
+import tempfile
 
 import numpy as np
 import pytest
@@ -201,31 +202,33 @@ def test_restore_resharded_tree_and_pipeline_state(tmp_path):
        cols=st.integers(min_value=1, max_value=7),
        n_parts=st.integers(min_value=1, max_value=5),
        n_procs=st.integers(min_value=1, max_value=3))
-def test_subshard_reassembly_roundtrip(tmp_path, rows, cols, n_parts,
-                                       n_procs):
-    n_parts = min(n_parts, rows)
-    rng = np.random.default_rng([rows, cols, n_parts, n_procs])
-    arr = rng.normal(size=(rows, cols)).astype(np.float32)
-    cuts = np.linspace(0, rows, n_parts + 1, dtype=int)
-    per_proc = [[] for _ in range(n_procs)]
-    for i in range(n_parts):
-        lo, hi = int(cuts[i]), int(cuts[i + 1])
-        if lo == hi:
-            continue
-        per_proc[i % n_procs].append(((lo, 0), arr[lo:hi]))
-    base = str(tmp_path / f"p{rows}x{cols}-{n_parts}-{n_procs}")
-    for pidx in range(n_procs):
-        tree = {"w": ckpt.SubShardLeaf.from_parts(arr.shape,
-                                                  per_proc[pidx])} \
-            if per_proc[pidx] else {"pad": np.float32(0.0)}
-        ckpt.save_sharded(base, tree, step=1, process_index=pidx,
-                          process_count=n_procs)
-    with reshard.CheckpointLayout.scan(base) as lay:
-        np.testing.assert_array_equal(lay.read_region("w"), arr)
-        # an arbitrary interior region reassembles across part seams
-        r0, r1 = rows // 3, max(rows // 3 + 1, (2 * rows) // 3)
-        got = lay.read_region("w", (slice(r0, r1), slice(0, cols)))
-        np.testing.assert_array_equal(got, arr[r0:r1])
+def test_subshard_reassembly_roundtrip(rows, cols, n_parts, n_procs):
+    # one fresh directory per example: a function-scoped tmp_path would
+    # be shared by every example hypothesis generates
+    with tempfile.TemporaryDirectory() as tmp:
+        n_parts = min(n_parts, rows)
+        rng = np.random.default_rng([rows, cols, n_parts, n_procs])
+        arr = rng.normal(size=(rows, cols)).astype(np.float32)
+        cuts = np.linspace(0, rows, n_parts + 1, dtype=int)
+        per_proc = [[] for _ in range(n_procs)]
+        for i in range(n_parts):
+            lo, hi = int(cuts[i]), int(cuts[i + 1])
+            if lo == hi:
+                continue
+            per_proc[i % n_procs].append(((lo, 0), arr[lo:hi]))
+        base = os.path.join(tmp, f"p{rows}x{cols}-{n_parts}-{n_procs}")
+        for pidx in range(n_procs):
+            tree = {"w": ckpt.SubShardLeaf.from_parts(arr.shape,
+                                                      per_proc[pidx])} \
+                if per_proc[pidx] else {"pad": np.float32(0.0)}
+            ckpt.save_sharded(base, tree, step=1, process_index=pidx,
+                              process_count=n_procs)
+        with reshard.CheckpointLayout.scan(base) as lay:
+            np.testing.assert_array_equal(lay.read_region("w"), arr)
+            # an arbitrary interior region reassembles across part seams
+            r0, r1 = rows // 3, max(rows // 3 + 1, (2 * rows) // 3)
+            got = lay.read_region("w", (slice(r0, r1), slice(0, cols)))
+            np.testing.assert_array_equal(got, arr[r0:r1])
 
 
 # ---------------------------------------------------------------------------

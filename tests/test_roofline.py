@@ -37,9 +37,7 @@ def test_scan_multiplies_by_trip_count():
     want = 2 * 64 * 128 * 128 * 17
     assert abs(c.flops - want) / want < 0.1, (c.flops, want)
     # XLA's own analysis undercounts (documents why hlocost exists)
-    from repro.analysis.roofline import xla_cost_dict
-
-    assert xla_cost_dict(comp).get("flops", 0.0) < 0.2 * want
+    assert comp.cost_analysis().get("flops", 0.0) < 0.2 * want
 
 
 def test_nested_scan():

@@ -99,7 +99,7 @@ def test_step_runner_aot_compile_once_and_cost():
     assert runner.n_traces == 1  # no retrace after AOT compile
     cost = runner.step_cost()   # hlocost over the optimized HLO
     assert cost is not None and cost.flops > 0
-    assert runner.mfu(0.1, B * S) > 0
+    assert runner.mfu(0.1, B * S, peak_flops=197e12) > 0
 
 
 def test_trainloop_telemetry_reports_single_compile():
