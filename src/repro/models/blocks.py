@@ -112,68 +112,74 @@ def apply_block(bp, shared, h, cfg: ModelConfig, spec: LayerSpec, *,
     cache = cache or {}
     p = shared[spec.shared_bank] if spec.kind == SHARED_ATTN else bp
 
-    # ---- mixer ----
-    x = apply_norm(p["ln1"], h, cfg)
-    if tp_ctx is not None:
-        x = tp_ctx["gather"](x)
-    if spec.kind == MAMBA:
-        mx, mc = apply_mamba(p["mixer"], x, cfg, mode=mode,
-                             cache=cache.get("mixer"), use_pallas=use_pallas)
-    elif spec.kind == MLA:
-        mx, mc = apply_mla(p["mixer"], x, cfg, spec, positions=positions,
-                           mode=mode, cache=cache.get("mixer"), pos=pos,
-                           use_pallas=use_pallas, dist=dist, paged=paged)
-    else:  # ATTN / SHARED_ATTN
-        mx, mc = apply_attn(p["mixer"], x, cfg, spec, positions=positions,
-                            mode=mode, cache=cache.get("mixer"), pos=pos,
-                            causal=causal, use_pallas=use_pallas, dist=dist,
-                            shard_ctx=shard_ctx, paged=paged)
-    if mc is not None:
-        new_cache["mixer"] = mc
-    if tp_ctx is not None:
-        mx = tp_ctx["scatter"](mx)
-    if cfg.post_norms and spec.kind != MAMBA and spec.kind != SHARED_ATTN:
-        mx = apply_norm(bp["post1"], mx, cfg)
-    h = h + mx
+    # ---- mixer ----  (each sublayer runs under a named scope, which the
+    # device trace's op_name carries through grad and remat)
+    with jax.named_scope("ssm" if spec.kind == MAMBA else "attention"):
+        x = apply_norm(p["ln1"], h, cfg)
+        if tp_ctx is not None:
+            x = tp_ctx["gather"](x)
+        if spec.kind == MAMBA:
+            mx, mc = apply_mamba(p["mixer"], x, cfg, mode=mode,
+                                 cache=cache.get("mixer"),
+                                 use_pallas=use_pallas)
+        elif spec.kind == MLA:
+            mx, mc = apply_mla(p["mixer"], x, cfg, spec, positions=positions,
+                               mode=mode, cache=cache.get("mixer"), pos=pos,
+                               use_pallas=use_pallas, dist=dist, paged=paged)
+        else:  # ATTN / SHARED_ATTN
+            mx, mc = apply_attn(p["mixer"], x, cfg, spec,
+                                positions=positions, mode=mode,
+                                cache=cache.get("mixer"), pos=pos,
+                                causal=causal, use_pallas=use_pallas,
+                                dist=dist, shard_ctx=shard_ctx, paged=paged)
+        if mc is not None:
+            new_cache["mixer"] = mc
+        if tp_ctx is not None:
+            mx = tp_ctx["scatter"](mx)
+        if cfg.post_norms and spec.kind != MAMBA and spec.kind != SHARED_ATTN:
+            mx = apply_norm(bp["post1"], mx, cfg)
+        h = h + mx
 
     # ---- cross attention (enc-dec decoders) ----
     if "cross" in (bp or {}):
-        x = apply_norm(bp["ln_cross"], h, cfg)
-        if mode == "decode":
-            kv = (cache["cross"]["k"], cache["cross"]["v"])
-        else:
-            kv = _cross_kv(bp["cross"], encoder_out, cfg)
-        cx, _ = apply_attn(bp["cross"], x, cfg, spec, positions=positions,
-                           mode=mode, cache=None, pos=pos,
-                           kv_override=kv, causal=False)
-        if mode == "decode":
-            new_cache["cross"] = cache["cross"]
-        elif mode == "prefill":
-            new_cache["cross"] = {"k": kv[0], "v": kv[1]}
-        h = h + cx
+        with jax.named_scope("attention"):
+            x = apply_norm(bp["ln_cross"], h, cfg)
+            if mode == "decode":
+                kv = (cache["cross"]["k"], cache["cross"]["v"])
+            else:
+                kv = _cross_kv(bp["cross"], encoder_out, cfg)
+            cx, _ = apply_attn(bp["cross"], x, cfg, spec, positions=positions,
+                               mode=mode, cache=None, pos=pos,
+                               kv_override=kv, causal=False)
+            if mode == "decode":
+                new_cache["cross"] = cache["cross"]
+            elif mode == "prefill":
+                new_cache["cross"] = {"k": kv[0], "v": kv[1]}
+            h = h + cx
 
     # ---- mlp / moe ----
     has_mlp = spec.has_mlp or spec.kind == SHARED_ATTN
     if has_mlp:
-        x = apply_norm(p["ln2"], h, cfg)
-        if spec.moe:
-            ctx = moe_ctx or {}
-            mx, moe_aux = apply_moe(p["moe"], x, cfg, **ctx)
-            aux = aux + moe_aux
-        elif tp_ctx is not None:
-            # column-parallel up (local d_ff slice) / row-parallel down:
-            # the output bias is deferred past the psum_scatter so it is
-            # added once, not once per model rank
-            mx = apply_mlp(p["mlp"], tp_ctx["gather"](x), cfg,
-                           bias_out=False)
-            mx = tp_ctx["scatter"](mx)
-            if "bo" in p["mlp"]:
-                mx = mx + p["mlp"]["bo"].astype(mx.dtype)
-        else:
-            mx = apply_mlp(p["mlp"], x, cfg)
-        if cfg.post_norms and spec.kind != SHARED_ATTN:
-            mx = apply_norm(bp["post2"], mx, cfg)
-        h = h + mx
+        with jax.named_scope("ffn"):
+            x = apply_norm(p["ln2"], h, cfg)
+            if spec.moe:
+                ctx = moe_ctx or {}
+                mx, moe_aux = apply_moe(p["moe"], x, cfg, **ctx)
+                aux = aux + moe_aux
+            elif tp_ctx is not None:
+                # column-parallel up (local d_ff slice) / row-parallel down:
+                # the output bias is deferred past the psum_scatter so it is
+                # added once, not once per model rank
+                mx = apply_mlp(p["mlp"], tp_ctx["gather"](x), cfg,
+                               bias_out=False)
+                mx = tp_ctx["scatter"](mx)
+                if "bo" in p["mlp"]:
+                    mx = mx + p["mlp"]["bo"].astype(mx.dtype)
+            else:
+                mx = apply_mlp(p["mlp"], x, cfg)
+            if cfg.post_norms and spec.kind != SHARED_ATTN:
+                mx = apply_norm(bp["post2"], mx, cfg)
+            h = h + mx
     return h, new_cache, aux
 
 

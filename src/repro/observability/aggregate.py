@@ -163,9 +163,12 @@ class StragglerMonitor:
         self.reports: List[Dict[str, Any]] = []
 
     def maybe_check(self, step: int) -> Optional[Dict[str, Any]]:
+        """The check at a multiple of ``every``, as a ``straggler_check``
+        span on the comm lane; None between checks."""
         if step % self.every:
             return None
-        return self.check(step)
+        with self.tracer.span("straggler_check", "comm", step=step):
+            return self.check(step)
 
     def check(self, step: int) -> Dict[str, Any]:
         vec = phase_vector(self.tracer.take_window(), self.phases)

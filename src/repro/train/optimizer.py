@@ -61,34 +61,36 @@ def adamw_update(c: AdamWConfig, grads, opt_state, params, *,
     (e.g. ``gradsync.fsdp_global_norm``, which psums shard contributions
     across the dp axes); left None, it is the local ``_global_norm``.
     """
-    step = opt_state["step"]
-    gnorm = grad_norm if grad_norm is not None else _global_norm(grads)
-    scale = jnp.minimum(1.0, c.grad_clip / jnp.maximum(gnorm, 1e-9)) \
-        if c.grad_clip else 1.0
-    lr = lr_at(c, step)
-    t = (step + 1).astype(jnp.float32)
-    bc1 = 1 - c.b1 ** t
-    bc2 = 1 - c.b2 ** t
+    with jax.named_scope("optimizer"):
+        step = opt_state["step"]
+        gnorm = grad_norm if grad_norm is not None else _global_norm(grads)
+        scale = jnp.minimum(1.0, c.grad_clip / jnp.maximum(gnorm, 1e-9)) \
+            if c.grad_clip else 1.0
+        lr = lr_at(c, step)
+        t = (step + 1).astype(jnp.float32)
+        bc1 = 1 - c.b1 ** t
+        bc2 = 1 - c.b2 ** t
 
-    def upd(p, g, mu, nu):
-        g = g.astype(jnp.float32) * scale
-        mu = c.b1 * mu + (1 - c.b1) * g
-        nu = c.b2 * nu + (1 - c.b2) * jnp.square(g)
-        mhat = mu / bc1
-        nhat = nu / bc2
-        step_vec = mhat / (jnp.sqrt(nhat) + c.eps)
-        pf = p.astype(jnp.float32)
-        if p.ndim >= 2:  # decay matrices only (norms/bias excluded)
-            step_vec = step_vec + c.weight_decay * pf
-        return (pf - lr * step_vec).astype(p.dtype), mu, nu
+        def upd(p, g, mu, nu):
+            g = g.astype(jnp.float32) * scale
+            mu = c.b1 * mu + (1 - c.b1) * g
+            nu = c.b2 * nu + (1 - c.b2) * jnp.square(g)
+            mhat = mu / bc1
+            nhat = nu / bc2
+            step_vec = mhat / (jnp.sqrt(nhat) + c.eps)
+            pf = p.astype(jnp.float32)
+            if p.ndim >= 2:  # decay matrices only (norms/bias excluded)
+                step_vec = step_vec + c.weight_decay * pf
+            return (pf - lr * step_vec).astype(p.dtype), mu, nu
 
-    flat_p, treedef = jax.tree_util.tree_flatten(params)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_mu = treedef.flatten_up_to(opt_state["mu"])
-    flat_nu = treedef.flatten_up_to(opt_state["nu"])
-    out = [upd(p, g, m, n) for p, g, m, n in zip(flat_p, flat_g, flat_mu, flat_nu)]
-    new_p = jax.tree_util.tree_unflatten(treedef, [o[0] for o in out])
-    new_mu = jax.tree_util.tree_unflatten(treedef, [o[1] for o in out])
-    new_nu = jax.tree_util.tree_unflatten(treedef, [o[2] for o in out])
-    new_state = {"mu": new_mu, "nu": new_nu, "step": step + 1}
-    return new_p, new_state, {"grad_norm": gnorm, "lr": lr}
+        flat_p, treedef = jax.tree_util.tree_flatten(params)
+        flat_g = treedef.flatten_up_to(grads)
+        flat_mu = treedef.flatten_up_to(opt_state["mu"])
+        flat_nu = treedef.flatten_up_to(opt_state["nu"])
+        out = [upd(p, g, m, n)
+               for p, g, m, n in zip(flat_p, flat_g, flat_mu, flat_nu)]
+        new_p = jax.tree_util.tree_unflatten(treedef, [o[0] for o in out])
+        new_mu = jax.tree_util.tree_unflatten(treedef, [o[1] for o in out])
+        new_nu = jax.tree_util.tree_unflatten(treedef, [o[2] for o in out])
+        new_state = {"mu": new_mu, "nu": new_nu, "step": step + 1}
+        return new_p, new_state, {"grad_norm": gnorm, "lr": lr}

@@ -633,105 +633,91 @@ class TrainLoop:
             i = start_step
             while i < steps:
                 try:
-                    # t_step0 anchors this iteration's "step" span; every
-                    # blocked component below hands the SAME perf_counter
-                    # readings to tracer.complete, so the trace is
-                    # bit-identical to the stall accounting
-                    t_step0 = tw = time.perf_counter()
-                    batch = next(it)
-                    t1 = time.perf_counter()
-                    blocked += t1 - tw
-                    tracer.complete("data_wait", "data", tw, t1)
+                    # the stall accounting reads the timed spans' own
+                    # ends, so the trace is bit-identical to it
+                    with tracer.step(i):
+                        with tracer.timed("data_wait", "data") as waited:
+                            batch = next(it)
+                        blocked += waited.seconds
 
-                    if i == start_step:
-                        if tokens_per_step is None:
-                            tok = batch["tokens"]
-                            tokens_per_step = int(tok.shape[0]
-                                                  * tok.shape[1])
-                        if self.aot_compile and runner.compiled is None:
-                            runner.compile(state, batch)
+                        if i == start_step:
+                            if tokens_per_step is None:
+                                tok = batch["tokens"]
+                                tokens_per_step = int(tok.shape[0]
+                                                      * tok.shape[1])
+                            if self.aot_compile and runner.compiled is None:
+                                runner.compile(state, batch)
 
-                    tw = time.perf_counter()
-                    state, metrics = runner(state, batch)
-                    tracer.complete("dispatch", "compute", tw,
-                                    time.perf_counter())
-                    # the host-kill window: step i dispatched, device
-                    # possibly still mid-backward
-                    fault_point("step", i)
+                        with tracer.span("dispatch", "compute"):
+                            state, metrics = runner(state, batch)
+                        # the host-kill window: step i dispatched, device
+                        # possibly still mid-backward
+                        fault_point("step", i)
 
-                    now = time.perf_counter()
-                    dt = now - t_iter
-                    t_iter = now
-                    if i > start_step:  # first iter is dominated by compile
-                        ema = dt if ema is None else 0.9 * ema + 0.1 * dt
+                        now = time.perf_counter()
+                        dt = now - t_iter
+                        t_iter = now
+                        # the first iteration is dominated by compile
+                        if i > start_step:
+                            ema = dt if ema is None else 0.9 * ema + 0.1 * dt
 
-                    if (i + 1) % self.log_every == 0 or i == start_step \
-                            or i == steps - 1:
-                        n = i - last_logged
-                        window = max(now - t_last_log, 1e-9)
-                        bsz = batch["tokens"].shape[0]
-                        step_t = ema if ema is not None else dt
-                        meta = {
-                            "step": i + 1,
-                            "samples_per_s": n * bsz / window,
-                            "tokens_per_s": n * tokens_per_step / window,
-                            "step_time_ema": step_t,
-                            "mfu": runner.mfu(step_t, tokens_per_step,
-                                              self.peak_flops),
-                        }
-                        async_metrics.push(meta, metrics)
-                        last_logged = i
-                        t_last_log = now
-                        # poll may force-resolve past the lag window, which
-                        # blocks on the device — account it as stall time
-                        tw = time.perf_counter()
-                        resolve_into_log(async_metrics.poll())
-                        t1 = time.perf_counter()
-                        blocked += t1 - tw
-                        tracer.complete("metrics_resolve", "metrics",
-                                        tw, t1)
-                        if self.metrics is not None:
-                            self.metrics.set_gauges(meta, prefix="train_")
-                            if self.metrics_jsonl:
-                                self.metrics.write_jsonl(
-                                    self.metrics_jsonl, step=i + 1)
+                        if (i + 1) % self.log_every == 0 or i == start_step \
+                                or i == steps - 1:
+                            n = i - last_logged
+                            window = max(now - t_last_log, 1e-9)
+                            bsz = batch["tokens"].shape[0]
+                            step_t = ema if ema is not None else dt
+                            meta = {
+                                "step": i + 1,
+                                "samples_per_s": n * bsz / window,
+                                "tokens_per_s": n * tokens_per_step / window,
+                                "step_time_ema": step_t,
+                                "mfu": runner.mfu(step_t, tokens_per_step,
+                                                  self.peak_flops),
+                            }
+                            async_metrics.push(meta, metrics)
+                            last_logged = i
+                            t_last_log = now
+                            # poll may force-resolve past the lag window,
+                            # which blocks on the device — stall time
+                            with tracer.timed("metrics_resolve",
+                                              "metrics") as resolved:
+                                resolve_into_log(async_metrics.poll())
+                            blocked += resolved.seconds
+                            if self.metrics is not None:
+                                self.metrics.set_gauges(meta, prefix="train_")
+                                if self.metrics_jsonl:
+                                    self.metrics.write_jsonl(
+                                        self.metrics_jsonl, step=i + 1)
 
-                    if self.journal is not None:
-                        # device->host snapshot of the completed step —
-                        # must happen before the next dispatch reuses the
-                        # donated buffers; the sync is the price of
-                        # single-step rollback granularity
-                        tw = time.perf_counter()
-                        self.journal.record(
-                            state, i + 1,
-                            pipeline.state_at(i + 1)
-                            if pipeline is not None else None)
-                        t1 = time.perf_counter()
-                        blocked += t1 - tw
-                        tracer.complete("journal_snapshot", "ckpt", tw, t1,
-                                        step=i + 1)
+                        if self.journal is not None:
+                            # device->host snapshot of the completed step —
+                            # must happen before the next dispatch reuses
+                            # the donated buffers; the sync is the price of
+                            # single-step rollback granularity
+                            with tracer.timed("journal_snapshot", "ckpt",
+                                              step=i + 1) as snap:
+                                self.journal.record(
+                                    state, i + 1,
+                                    pipeline.state_at(i + 1)
+                                    if pipeline is not None else None)
+                            blocked += snap.seconds
 
-                    if (self.ckpt_path or self.ckpt_dir) and self.ckpt_every \
-                            and (i + 1) % self.ckpt_every == 0:
-                        tw = time.perf_counter()
-                        write_ckpt(state, i + 1)
-                        t1 = time.perf_counter()
-                        blocked += t1 - tw
-                        tracer.complete("ckpt_commit", "ckpt", tw, t1,
-                                        step=i + 1)
-                        last_saved = i + 1
+                        if (self.ckpt_path or self.ckpt_dir) \
+                                and self.ckpt_every \
+                                and (i + 1) % self.ckpt_every == 0:
+                            with tracer.timed("ckpt_commit", "ckpt",
+                                              step=i + 1) as commit:
+                                write_ckpt(state, i + 1)
+                            blocked += commit.seconds
+                            last_saved = i + 1
 
-                    t1 = time.perf_counter()
-                    tracer.complete("step", "loop", t_step0, t1, step=i)
                     if step_hist is not None and i > start_step:
                         step_hist.observe(dt * 1e3)
                     if monitor is not None:
                         # deterministic schedule: every rank reaches this
                         # allgather at the same completed-step count
-                        tw = time.perf_counter()
-                        if monitor.maybe_check(i + 1) is not None:
-                            tracer.complete("straggler_check", "comm", tw,
-                                            time.perf_counter(), step=i + 1)
+                        monitor.maybe_check(i + 1)
                 except TransientWorkerError:
                     if self.journal is None or pipeline is None \
                             or self.journal.latest() is None \
@@ -766,29 +752,26 @@ class TrainLoop:
             # every still-pending metric window at once, a cost paid once
             # at exit.  Account it separately (telemetry['drain_s']) so
             # stall_fraction keeps meaning "host blocked per steady step".
-            tw = time.perf_counter()
-            resolve_into_log(async_metrics.drain())
-            t_drained = time.perf_counter()
-            drain_s = t_drained - tw
-            tracer.complete("metrics_drain", "metrics", tw, t_drained)
-            jax.block_until_ready(state)
-            t_blocked = time.perf_counter()
-            tracer.complete("device_block", "compute", t_drained, t_blocked)
+            with tracer.timed("metrics_drain", "metrics") as drained:
+                resolve_into_log(async_metrics.drain())
+            drain_s = drained.seconds
+            with tracer.timed("device_block", "compute") as waited:
+                jax.block_until_ready(state)
+            blocked += waited.seconds
             # steps > start_step: a resumed run that had nothing to do must
             # not rewrite (or mislabel) an existing checkpoint with the
             # restored state under a different step number
             final_ckpt = (self.ckpt_path or self.ckpt_dir) \
                 and last_saved != steps and steps > start_step
-            if final_ckpt:
-                write_ckpt(state, steps)
-            if saver is not None:
-                saver.close()
-                saver = None
-            t1 = time.perf_counter()
-            if final_ckpt:
-                tracer.complete("ckpt_commit", "ckpt", t_blocked, t1,
-                                step=steps)
-            blocked += t1 - t_drained
+            if final_ckpt or saver is not None:
+                with tracer.timed("ckpt_commit", "ckpt",
+                                  step=steps) as commit:
+                    if final_ckpt:
+                        write_ckpt(state, steps)
+                    if saver is not None:
+                        saver.close()
+                        saver = None
+                blocked += commit.seconds
         finally:
             if saver is not None:  # exception path: still flush the queue
                 saver.close()

@@ -144,8 +144,9 @@ def chunked_xent(params, h, labels, loss_mask, cfg, *, chunk: int = 512,
         labels.reshape(B, n, c).transpose(1, 0, 2),
         loss_mask.reshape(B, n, c).transpose(1, 0, 2),
     )
-    (s_nll, s_acc, s_den), _ = jax.lax.scan(
-        one, (jnp.zeros((), jnp.float32),) * 3, xs)
+    with jax.named_scope("loss_head"):
+        (s_nll, s_acc, s_den), _ = jax.lax.scan(
+            one, (jnp.zeros((), jnp.float32),) * 3, xs)
     return s_nll, s_acc, s_den
 
 
@@ -213,12 +214,6 @@ def loss_for(model: Model, params, batch, *, run: RunConfig,
         moe_ctx = _moe_ctx(model, mesh, run, batch["tokens"].shape[0])
         if moe_ctx is not None and axis_names is not None:
             moe_ctx = {**moe_ctx, "stat_axes": axis_names}
-    h, _, aux = model.apply(
-        params, batch, mode="train", remat=run.remat,
-        use_pallas=run.use_pallas, act_dtype=_act_dtype(run),
-        moe_ctx=moe_ctx, tp_ctx=tp_ctx,
-        constrain=constrain, return_hidden=True, shard_ctx=shard_ctx,
-    )
     labels = batch["labels"]
     mask = batch.get("loss_mask")
     if mask is None:
@@ -230,8 +225,19 @@ def loss_for(model: Model, params, batch, *, run: RunConfig,
         n_shards = int(_np.prod([mesh.shape[a] for a in bax])) if bax else 1
     c = loss_chunk_len(labels.shape[0], labels.shape[1], cfg.vocab_size,
                        n_shards)
-    s_nll, s_acc, s_den = chunked_xent(params, h, labels, mask, cfg,
-                                       chunk=c, use_pallas=run.use_pallas)
+    # what is differentiated runs under one named scope: on the device its
+    # ops read jvp(step_forward) (forward), transpose(jvp(step_forward))
+    # (backward) and .../rematted_computation/... (recomputation)
+    with jax.named_scope("step_forward"):
+        h, _, aux = model.apply(
+            params, batch, mode="train", remat=run.remat,
+            use_pallas=run.use_pallas, act_dtype=_act_dtype(run),
+            moe_ctx=moe_ctx, tp_ctx=tp_ctx,
+            constrain=constrain, return_hidden=True, shard_ctx=shard_ctx,
+        )
+        s_nll, s_acc, s_den = chunked_xent(params, h, labels, mask, cfg,
+                                           chunk=c,
+                                           use_pallas=run.use_pallas)
     if axis_names is not None:
         # global denominator: mask-only, so safe inside value_and_grad
         # (its transpose never touches params)
@@ -863,22 +869,24 @@ def _pipeline_parts(model: Model, run: RunConfig, plan: ParallelPlan):
     def stage_fwd(params, x_recv, mb, is_first):
         toks = mb["tokens"]
         positions = jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
-        h = embed_tokens(params["embed"], toks, cfg, act_dtype)
-        h = add_positions(params["embed"], h, positions, cfg)
-        h = jnp.where(is_first, h, x_recv)
-        h, _, _ = apply_group(
-            params["groups"][0], None, h, cfg, local_group,
-            positions=positions, mode="train", causal=causal,
-            remat=run.remat, use_pallas=run.use_pallas)
+        with jax.named_scope("step_forward"):
+            h = embed_tokens(params["embed"], toks, cfg, act_dtype)
+            h = add_positions(params["embed"], h, positions, cfg)
+            h = jnp.where(is_first, h, x_recv)
+            h, _, _ = apply_group(
+                params["groups"][0], None, h, cfg, local_group,
+                positions=positions, mode="train", causal=causal,
+                remat=run.remat, use_pallas=run.use_pallas)
         return h
 
     def stage_loss(params, y, mb):
-        h = apply_norm(params["final_norm"], y, cfg)
         mask = mb.get("loss_mask")
         if mask is None:
             mask = jnp.ones(mb["labels"].shape, jnp.float32)
-        return chunked_xent(params, h, mb["labels"], mask, cfg,
-                            chunk=chunk, use_pallas=run.use_pallas)
+        with jax.named_scope("step_forward"):
+            h = apply_norm(params["final_norm"], y, cfg)
+            return chunked_xent(params, h, mb["labels"], mask, cfg,
+                                chunk=chunk, use_pallas=run.use_pallas)
 
     rows = plan.local_batch // plan.n_micro
     act_shape = (rows, run.shape.seq_len, cfg.d_model)

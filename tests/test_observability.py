@@ -109,8 +109,8 @@ def test_ring_buffer_drops_oldest():
     xs = [e for e in tr.chrome_events() if e["ph"] == "X"]
     # the survivors are exactly the NEWEST 8, in order
     assert [e["args"]["i"] for e in xs] == list(range(17, 25))
-    # totals still account every event (they are not ring-bound)
-    assert tr.totals["ev"] == pytest.approx(25e-6)
+    # the straggler window still accounts every event (not ring-bound)
+    assert tr.take_window()["ev"] == pytest.approx(25e-6)
 
 
 def test_async_events_pair_and_instants():
@@ -136,7 +136,6 @@ def test_take_window_accumulates_and_resets():
     assert w == {"data_wait": pytest.approx(0.5),
                  "dispatch": pytest.approx(0.125)}
     assert tr.take_window() == {}            # reset
-    assert tr.totals["data_wait"] == pytest.approx(0.5)  # totals persist
 
 
 def test_null_tracer_is_inert_and_default():
@@ -470,6 +469,98 @@ def test_trainloop_trace_covers_wall_and_matches_telemetry():
     assert reg["train_stall_fraction"].value \
         == pytest.approx(t["stall_fraction"])
     assert any(n.startswith("grad_") for n in reg.names())
+
+
+LOOP_SPANS = ("step", "data_wait", "dispatch", "metrics_resolve",
+              "metrics_drain", "device_block")
+
+
+def _profiled_loop(runner, tracer, trace_dir, steps=4):
+    """``steps`` loop iterations under a profiler session; the host
+    plane's loop-span events."""
+    loop = TrainLoop(runner, log_every=2, tracer=tracer,
+                     device_prefetch=False)
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        loop.run(_batches(), steps)
+    finally:
+        jax.profiler.stop_trace()
+    sc = _load_tool("span_clock")
+    return sc, sc.host_events(str(trace_dir), LOOP_SPANS + ("train_step",))
+
+
+def test_loop_spans_mirrored_on_the_profilers_clock(tmp_path):
+    """With a Tracer installed each loop span is also a profiler
+    annotation: once on the host plane, of the same duration, at one
+    offset between the two clocks (what lines the benchmark's host
+    spans up with the device's operations).  With the NullTracer the
+    profiler sees none of them."""
+    model, run, opt = _fixture()
+    runner = StepRunner(model, run, opt, make_host_mesh(1, 1))
+    TrainLoop(runner, device_prefetch=False).run(_batches(), 1)  # compile
+    tracer = Tracer()
+    sc, host = _profiled_loop(runner, tracer, tmp_path / "on")
+    spans = sc.tracer_spans(tracer.chrome_events())
+    assert set(spans) == set(LOOP_SPANS)
+    for name in LOOP_SPANS:
+        assert len(host[name]) == len(spans[name]), name
+    # each iteration is also the profiler's step, numbered from 0
+    assert len(host["train_step"]) == len(spans["step"]) == 4
+    pairs = sc.pair({n: host[n] for n in LOOP_SPANS}, spans)
+    offsets = []
+    for name in LOOP_SPANS:
+        assert len(pairs[name]) == len(spans[name]), name
+        for offset, gap in pairs[name]:
+            assert abs(gap) <= 50.0, (name, gap)          # us
+            offsets.append(offset)
+    assert max(offsets) - min(offsets) <= 100.0             # us
+
+    prev = set_tracer(None)
+    try:
+        _, host = _profiled_loop(runner, None, tmp_path / "off")
+    finally:
+        set_tracer(prev)
+    assert not any(host.values()), {n: len(v) for n, v in host.items()}
+
+
+def test_untraced_loop_reads_the_clock_three_times_a_step(monkeypatch):
+    """The NullTracer's loop enters no profiler annotation and reads
+    ``perf_counter`` three times an iteration between log steps: the
+    data wait's two ends (the stall accounting) and the step clock."""
+    import repro.observability.trace as trace_mod
+    import repro.train.runner as runner_mod
+
+    class Clock:
+        n = 0
+
+        def __getattr__(self, name):
+            return getattr(time, name)
+
+        def perf_counter(self):
+            Clock.n += 1
+            return time.perf_counter()
+
+    def refuse(*a, **k):
+        raise AssertionError("a profiler annotation with tracing off")
+
+    model, run, opt = _fixture()
+    runner = StepRunner(model, run, opt, make_host_mesh(1, 1))
+    TrainLoop(runner, device_prefetch=False).run(_batches(), 1)  # compile
+    monkeypatch.setattr(runner_mod, "time", Clock())
+    monkeypatch.setattr(trace_mod, "time", Clock())
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", refuse)
+    prev = set_tracer(None)
+    try:
+        reads = []
+        for steps in (3, 7):
+            Clock.n = 0
+            TrainLoop(runner, log_every=100, tracer=NULL_TRACER,
+                      device_prefetch=False).run(_batches(), steps)
+            reads.append(Clock.n)
+    finally:
+        set_tracer(prev)
+    assert (reads[1] - reads[0]) / 4 == 3
 
 
 def test_trainloop_straggler_monitor_single_process():
