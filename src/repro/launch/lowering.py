@@ -85,15 +85,18 @@ def _pallas_costs(run, mesh, shape, *, causal: bool):
     if shape.mode == "train":
         # fused xent: one (B_loc * chunk, V) logits block read + (T,) write
         from repro.analysis.hlocost import Cost
-        from repro.train.train_step import loss_chunk_len
+        from repro.train.train_step import loss_blocks, loss_chunk_len
 
         bax = shd.batch_axes(mesh, shape.global_batch, run.sharding)
         n_sh = 1
         for a in bax:
             n_sh *= mesh.shape[a]
         b_loc = max(1, shape.global_batch // n_sh)
-        c = loss_chunk_len(shape.global_batch, shape.seq_len,
-                           run.model.vocab_size, n_sh)
+        # the loss head's block length (its segments tile with it where S
+        # splits evenly); the cost model counts every segment, each the
+        # costlier branch of its cond
+        _, c = loss_blocks(shape.seq_len, loss_chunk_len(
+            shape.global_batch, shape.seq_len, run.model.vocab_size, n_sh))
         V = run.model.vocab_size
         Vl = V // mesh.shape.get("model", 1) if V % mesh.shape.get(
             "model", 1) == 0 and run.sharding in ("tp", "fsdp_tp") else V

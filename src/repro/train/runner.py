@@ -609,6 +609,9 @@ class TrainLoop:
                 log.tokens_per_s.append(meta["tokens_per_s"])
                 log.step_time_ema.append(meta["step_time_ema"])
                 log.mfu.append(meta["mfu"])
+                if self.metrics is not None:
+                    # the step's own metrics (train_xent, train_loss_rows)
+                    self.metrics.set_gauges(m, prefix="train_")
 
         last_saved = -1
 

@@ -93,13 +93,48 @@ LOSS_TARGET_BYTES = 512e6  # per-device f32 logits per loss block
 
 def loss_chunk_len(global_batch: int, seq: int, vocab: int,
                    n_batch_shards: int) -> int:
-    """Seq positions per loss block so per-device f32 logits stay ~512MB.
-    Chunking along SEQ preserves the batch sharding (chunking flattened
-    global tokens would serialize the loss across devices)."""
+    """The most seq positions a loss block may hold so per-device f32
+    logits stay ~512MB (``loss_blocks`` tiles the rows with blocks of at
+    most this length).  Chunking along SEQ preserves the batch sharding
+    (chunking flattened global tokens would serialize the loss across
+    devices)."""
     b_loc = max(1, global_batch // max(1, n_batch_shards))
     per_pos = b_loc * vocab * 4.0
     c = int(LOSS_TARGET_BYTES // per_pos)
     return max(8, min(seq, c))
+
+
+def loss_blocks(rows: int, chunk: int) -> Tuple[int, int]:
+    """``(n, c)``: ``n`` loss blocks of ``c <= chunk`` positions for
+    ``rows`` positions.  The fewest blocks, up to twice the least number,
+    that tile ``rows`` exactly; failing that, the least number of blocks
+    of nearly equal length, the last padded by fewer than ``n``."""
+    n = -(-rows // max(1, chunk))
+    for m in range(n, 2 * n + 1):
+        if rows % m == 0:
+            return m, rows // m
+    return n, -(-rows // n)
+
+
+def loss_capacities(seq: int) -> Tuple[int, ...]:
+    """The row capacities the loss head may run at: S/8, S/4 and S/2,
+    each rounded up to a multiple of 8, then S itself."""
+    up8 = lambda x: -(-x // 8) * 8
+    caps = {min(seq, up8(-(-seq // k))) for k in (8, 4, 2)}
+    return tuple(sorted(caps | {seq}))
+
+
+def _compact_index(loss_mask):
+    """Per row, the positions of its nonzero ``loss_mask`` entries in
+    order, then the last position repeated, and which slots hold one of
+    the row's entries."""
+    S = loss_mask.shape[1]
+    cs = jnp.cumsum(loss_mask != 0, axis=1, dtype=jnp.int32)
+    slot = jnp.arange(S, dtype=jnp.int32)
+    # slot k holds the position p with cs[p] == k + 1: the count of
+    # positions before it, which all have cs <= k (S past the row's last)
+    idx = (cs[:, None, :] <= slot[None, :, None]).sum(-1, dtype=jnp.int32)
+    return jnp.minimum(idx, S - 1), idx < S
 
 
 def chunked_xent(params, h, labels, loss_mask, cfg, *, chunk: int = 512,
@@ -108,17 +143,22 @@ def chunked_xent(params, h, labels, loss_mask, cfg, *, chunk: int = 512,
     materializing the full (B, S, V) logits.  With ``use_pallas`` the
     per-block nll comes from the fused_xent Pallas kernel (no (c, V)
     log-prob temp at all); otherwise the jnp analogue.
-    Returns (sum_nll, sum_correct, denom)."""
+
+    Only positions with a nonzero ``loss_mask`` count, so the head runs
+    on those alone: each row's masked positions are gathered to its
+    front, in order (the gather's transpose scatters the gradient back),
+    and the rows are cut at ``loss_capacities(S)`` into segments, each a
+    scan over ``loss_blocks`` of at most ``chunk``.  The segments up to
+    the smallest capacity that holds every row's count run; those past
+    it hold weight 0 only and are skipped (``lax.cond``).  When a count
+    passes S/2 every segment runs: the sums are the same for any mask.
+    Returns (sum_nll, sum_correct, denom, rows), ``rows`` the capacity
+    taken over S."""
     from repro.models.transformer import head_apply
 
     B, S, d = h.shape
-    c = min(chunk, S)
-    pad = (-S) % c
-    if pad:
-        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, pad)))
-        loss_mask = jnp.pad(loss_mask, ((0, 0), (0, pad)))
-    n = (S + pad) // c
+    caps = loss_capacities(S)
+    zeros = (jnp.zeros((), jnp.float32),) * 3
 
     @jax.checkpoint
     def one(carry, xs):
@@ -139,15 +179,38 @@ def chunked_xent(params, h, labels, loss_mask, cfg, *, chunk: int = 512,
         return (s_nll + (nll * mb).sum(), s_acc + acc.sum(),
                 s_den + mb.sum()), None
 
-    xs = (
-        h.reshape(B, n, c, d).transpose(1, 0, 2, 3),
-        labels.reshape(B, n, c).transpose(1, 0, 2),
-        loss_mask.reshape(B, n, c).transpose(1, 0, 2),
-    )
+    def segment(h, labels, loss_mask):
+        n, c = loss_blocks(h.shape[1], chunk)
+        pad = n * c - h.shape[1]
+        if pad:
+            h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+            labels = jnp.pad(labels, ((0, 0), (0, pad)))
+            loss_mask = jnp.pad(loss_mask, ((0, 0), (0, pad)))
+        xs = (
+            h.reshape(B, n, c, d).transpose(1, 0, 2, 3),
+            labels.reshape(B, n, c).transpose(1, 0, 2),
+            loss_mask.reshape(B, n, c).transpose(1, 0, 2),
+        )
+        return jax.lax.scan(one, zeros, xs)[0]
+
     with jax.named_scope("loss_head"):
-        (s_nll, s_acc, s_den), _ = jax.lax.scan(
-            one, (jnp.zeros((), jnp.float32),) * 3, xs)
-    return s_nll, s_acc, s_den
+        need = (loss_mask != 0).sum(axis=1, dtype=jnp.int32).max()
+        case = (need > jnp.asarray(caps[:-1], jnp.int32)).sum(
+            dtype=jnp.int32)
+        idx, held = _compact_index(loss_mask)
+        take = lambda x: jnp.take_along_axis(
+            x, idx if x.ndim == 2 else idx[..., None], axis=1,
+            mode="promise_in_bounds")
+        h, labels = take(h), take(labels)
+        loss_mask = jnp.where(held, take(loss_mask), 0)
+        sums = zeros
+        for i, (a, b) in enumerate(zip((0,) + caps[:-1], caps)):
+            part = (h[:, a:b], labels[:, a:b], loss_mask[:, a:b])
+            got = segment(*part) if i == 0 else jax.lax.cond(
+                case >= i, segment, lambda *_: zeros, *part)
+            sums = tuple(x + y for x, y in zip(sums, got))
+        rows = jnp.asarray(caps, jnp.float32)[case] / S
+    return (*sums, rows)
 
 
 def build_attn_ctx(cfg, mesh, run: RunConfig, global_batch: int,
@@ -235,9 +298,9 @@ def loss_for(model: Model, params, batch, *, run: RunConfig,
             moe_ctx=moe_ctx, tp_ctx=tp_ctx,
             constrain=constrain, return_hidden=True, shard_ctx=shard_ctx,
         )
-        s_nll, s_acc, s_den = chunked_xent(params, h, labels, mask, cfg,
-                                           chunk=c,
-                                           use_pallas=run.use_pallas)
+        s_nll, s_acc, s_den, rows = chunked_xent(
+            params, h, labels, mask, cfg, chunk=c,
+            use_pallas=run.use_pallas)
     if axis_names is not None:
         # global denominator: mask-only, so safe inside value_and_grad
         # (its transpose never touches params)
@@ -245,15 +308,18 @@ def loss_for(model: Model, params, batch, *, run: RunConfig,
         den = jnp.maximum(g_den, 1.0)
         loss = s_nll / den + aux / dp_size
         # metric reductions are dead-end branches for the cotangent
-        g_nll, g_acc, g_aux = jax.lax.psum((s_nll, s_acc, aux), axis_names)
+        g_nll, g_acc, g_aux, g_rows = jax.lax.psum(
+            (s_nll, s_acc, aux, rows), axis_names)
         xent = g_nll / den
         metrics = {"xent": xent, "acc": g_acc / den, "tokens": g_den,
+                   "loss_rows": g_rows / jax.lax.psum(1, axis_names),
                    "aux_loss": g_aux / dp_size,
                    "loss": xent + g_aux / dp_size}
         return loss, metrics
     den = jnp.maximum(s_den, 1.0)
     loss = s_nll / den
-    metrics = {"xent": loss, "acc": s_acc / den, "tokens": s_den}
+    metrics = {"xent": loss, "acc": s_acc / den, "tokens": s_den,
+               "loss_rows": rows}
     loss = loss + aux
     metrics["aux_loss"] = aux
     metrics["loss"] = loss
@@ -886,7 +952,7 @@ def _pipeline_parts(model: Model, run: RunConfig, plan: ParallelPlan):
         with jax.named_scope("step_forward"):
             h = apply_norm(params["final_norm"], y, cfg)
             return chunked_xent(params, h, mb["labels"], mask, cfg,
-                                chunk=chunk, use_pallas=run.use_pallas)
+                                chunk=chunk, use_pallas=run.use_pallas)[:3]
 
     rows = plan.local_batch // plan.n_micro
     act_shape = (rows, run.shape.seq_len, cfg.d_model)
