@@ -104,12 +104,13 @@ def main(argv=None) -> int:
 
 
 def leaf_names(c):
-    from bench.reference import bert_mlm
-
     import jax
 
+    from bench import harness
+    from bench.reference import core
+
     flat, _ = jax.tree_util.tree_flatten_with_path(
-        bert_mlm.param_shapes(c), is_leaf=bert_mlm._is_shape)
+        harness.model_of(c).param_shapes(c), is_leaf=core.is_shape)
     return [jax.tree_util.keystr(k) for k, _ in flat]
 
 

@@ -4,12 +4,15 @@ comparison with the reference, and the result line.
 ``run.py`` calls them in order; the tests call them one by one on the
 CPU at small sizes.  Everything that belongs to one configuration, one
 traffic mix, one cell or one per-layer metric is a file of its own under
-``bench/``, found by the name ``BENCHMARK.json`` gives it.
+``bench/``, found by the name ``BENCHMARK.json`` gives it; a
+configuration's model (reference, program config and counts) is the
+module its file's ``reference`` key names (:func:`model_of`).
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -22,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import bert_mlm as ref_model
+from bench.reference import core
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -79,8 +82,10 @@ def chips(n: int) -> List[Any]:
     return devices[:n]
 
 
+@functools.lru_cache(maxsize=None)
 def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` as a module (metric readers, sources)."""
+    """``bench/<kind>/<name>.py`` as a module (metric readers, sources,
+    models), loaded once a process."""
     path = BENCH / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('-', '_').replace('.', '_')}", path)
@@ -89,20 +94,16 @@ def load_module(kind: str, name: str):
     return mod
 
 
-def program_config(c: Dict[str, Any]):
-    """The program's ModelConfig for configuration file ``c``: its
-    published config with the file's sizes."""
-    from repro.configs import get_config
-    from repro.configs.base import ATTN, LayerSpec, uniform_schedule
+def model_of(c: Dict[str, Any]):
+    """The model module of configuration file ``c``:
+    ``bench/reference/<c["reference"]>.py``, whose interface
+    ``bench/reference/__init__.py`` states."""
+    return load_module("reference", c["reference"])
 
-    base = get_config(c["program_arch"])
-    d, h = c["hidden_size"], c["num_attention_heads"]
-    return dataclasses.replace(
-        base, d_model=d, n_heads=h, n_kv_heads=h, head_dim=d // h,
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        schedule=uniform_schedule(c["num_hidden_layers"], LayerSpec(ATTN)),
-        max_position=c["max_position_embeddings"],
-        norm_eps=c["layer_norm_eps"])
+
+def program_config(c: Dict[str, Any]):
+    """The program's ModelConfig for configuration file ``c``."""
+    return model_of(c).program_config(c)
 
 
 # ---------------------------------------------------------------------------
@@ -178,13 +179,15 @@ def make_mesh(devices):
 def init_state(c, seed: int, shardings):
     """The train state from ``seed``, made on the device in one call: the
     reference's weights, zero moments, step 0."""
+    init_params = model_of(c).init_params
+
     def make(key):
-        p = ref_model.init_params(c, key)
+        p = init_params(c, key)
         z = jax.tree_util.tree_map(jnp.zeros_like, p)
         return {"params": p, "opt": {"mu": z, "nu": z,
                                      "step": jnp.zeros((), jnp.int32)}}
 
-    return jax.jit(make, out_shardings=shardings)(ref_model.seed_key(seed))
+    return jax.jit(make, out_shardings=shardings)(core.seed_key(seed))
 
 
 def make_runner(spec: Spec, devices):
@@ -207,7 +210,7 @@ def make_runner(spec: Spec, devices):
         else make_mesh(devices)
     want = jax.tree_util.tree_structure(model.abstract())
     got = jax.tree_util.tree_structure(
-        ref_model.param_shapes(c), is_leaf=ref_model._is_shape)
+        model_of(c).param_shapes(c), is_leaf=core.is_shape)
     if want != got:
         raise ValueError(f"the reference's weights do not have the "
                          f"program's layout:\n{got}\n!=\n{want}")
@@ -254,7 +257,7 @@ def first_grad_norms(o, mu, grad_norm: float) -> List[float]:
     with ``norm`` the global norm the program clipped by."""
     scale = min(1.0, o["grad_clip"] / max(grad_norm, 1e-9)) \
         if o["grad_clip"] else 1.0
-    return [x / (1 - o["b1"]) / scale for x in ref_model.leaf_norms(mu)]
+    return [x / (1 - o["b1"]) / scale for x in core.leaf_norms(mu)]
 
 
 def setup_steps(s: Setup, n_ref: int, n_warm: int):
@@ -262,6 +265,7 @@ def setup_steps(s: Setup, n_ref: int, n_warm: int):
     readings for the comparison, then ``n_warm`` more as warm-up."""
     c = s.spec.config
     o = c["optimizer"]
+    init_params = model_of(c).init_params
     losses = []
     for i in range(n_ref):
         log = run_steps(s, 1)
@@ -269,10 +273,10 @@ def setup_steps(s: Setup, n_ref: int, n_warm: int):
         if i == 0:
             s.readings["grad_norms"] = first_grad_norms(
                 o, s.state["opt"]["mu"], log.metrics[-1]["grad_norm"])
-    s.readings["change_norms"] = ref_model.leaf_norms(jax.jit(
+    s.readings["change_norms"] = core.leaf_norms(jax.jit(
         lambda p, key: jax.tree_util.tree_map(
-            jnp.subtract, p, ref_model.init_params(c, key)))(
-                s.state["params"], ref_model.seed_key(s.seed)))
+            jnp.subtract, p, init_params(c, key)))(
+                s.state["params"], core.seed_key(s.seed)))
     s.readings["losses"] = losses
     if n_warm:
         run_steps(s, n_warm)
@@ -368,12 +372,14 @@ def reference_readings(spec: Spec, seed: int, batches, devices, *,
     """The reference followed through ``batches`` from ``seed``, its
     float32 matmuls at ``precision``: unless given, the one the
     configuration states (``matmul_precision``), as the program runs."""
-    ref = ref_model.Reference(spec.config, dtype=dtype, devices=devices,
-                              block_rows=spec.cell["reference_block_rows"],
-                              precision=precision
-                              or spec.config["matmul_precision"])
-    return ref_model.follow(spec.config, seed, batches, ref=ref, rows=rows,
-                            global_den=global_den)
+    m = model_of(spec.config)
+    ref = core.Reference(spec.config, m.nll_sum, m.init_params, dtype=dtype,
+                         devices=devices,
+                         block_rows=spec.cell["reference_block_rows"],
+                         precision=precision
+                         or spec.config["matmul_precision"])
+    return core.follow(spec.config, seed, batches, ref=ref, rows=rows,
+                       global_den=global_den)
 
 
 # a leaf whose reference gradient is under this share of the median

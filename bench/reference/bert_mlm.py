@@ -1,6 +1,9 @@
-"""Plain BERT-MLM in jax.numpy: weights, masking, forward, loss, gradients
-and AdamW.  It imports nothing of the program and takes nothing the
-program made.
+"""Plain BERT-MLM in jax.numpy: weights, masking, forward and loss, with
+the program's config and the step's counts for the same configuration
+file.  The reference imports nothing of the program and takes nothing
+the program made; ``program_config`` alone names the program, to build
+its side of the comparison.  Gradients in blocks of rows and AdamW are
+``bench.reference.core``'s, as for every model.
 
 The architecture is the one the configuration file states, which departs
 from BERT (arXiv:1810.04805) where the program does: LayerNorm before
@@ -20,21 +23,15 @@ weights and optimizer.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-
-def seed_key(seed: int):
-    """A PRNG key from any seed below 2**62, wider than 32 bits too.
-    Make it outside ``jax.jit`` and pass it in: a seed baked into a
-    program as a constant makes a new program, compiled anew, per seed."""
-    key = jax.random.PRNGKey(0)
-    key = jax.random.fold_in(key, seed & 0x7FFFFFFF)
-    return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
+from bench.flops import model_flops_per_step, step_matmuls  # noqa: F401
+from bench.reference.core import is_shape, seed_key  # noqa: F401
 
 
 def _dims(c):
@@ -65,15 +62,11 @@ def param_shapes(c) -> Dict[str, Any]:
                     "out_bias": ((V,), z)}}
 
 
-def _is_shape(x):
-    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
-
-
 def init_params(c, key):
     """BERT initialisation from ``key`` (``seed_key``'s); call it under
     ``jax.jit``."""
     shapes = param_shapes(c)
-    leaves, treedef = jax.tree_util.tree_flatten(shapes, is_leaf=_is_shape)
+    leaves, treedef = jax.tree_util.tree_flatten(shapes, is_leaf=is_shape)
     std = c["initializer_range"]
     out = []
     for i, (shape, kind) in enumerate(leaves):
@@ -159,139 +152,21 @@ def nll_sum(params, batch, c, dtype=jnp.float32):
 
 
 # ---------------------------------------------------------------------------
-# gradients in blocks of rows, and AdamW
+# the program's side
 # ---------------------------------------------------------------------------
 
 
-class Reference:
-    """Gradients and AdamW steps of the reference, computed in blocks of
-    ``block_rows`` rows so that a batch of any size fits.  ``devices``
-    spreads each block's rows over several chips (weights replicated)."""
+def program_config(c: Dict[str, Any]):
+    """The program's ModelConfig for configuration file ``c``: its
+    published config with the file's sizes."""
+    from repro.configs import get_config
+    from repro.configs.base import ATTN, LayerSpec, uniform_schedule
 
-    def __init__(self, c, *, dtype=jnp.float32, block_rows: int,
-                 devices: Sequence[Any], precision: str = "highest"):
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-        if block_rows % len(devices):
-            raise ValueError(f"{block_rows} rows a block do not split over "
-                             f"{len(devices)} devices")
-        self.c, self.dtype, self.block_rows = c, dtype, block_rows
-        mesh = Mesh(np.array(devices), ("rows",))
-        self.rep = NamedSharding(mesh, P())
-        self.rows = NamedSharding(mesh, P("rows"))
-        if dtype != jnp.float32:
-            precision = "default"
-
-        def grad_block(params, blk):
-            with jax.default_matmul_precision(precision):
-                (s, n), g = jax.value_and_grad(
-                    lambda p: nll_sum(p, blk, c, dtype), has_aux=True)(params)
-            g = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), g)
-            return s, n, g
-
-        self._grad_block = jax.jit(grad_block, out_shardings=self.rep)
-        self._add = jax.jit(lambda a, b: jax.tree_util.tree_map(
-            jnp.add, a, b), donate_argnums=(0,))
-        self._scale = jax.jit(lambda g, k: jax.tree_util.tree_map(
-            lambda x: x * k, g), donate_argnums=(0,))
-
-    def params(self, seed: int):
-        return jax.jit(lambda k: init_params(self.c, k),
-                       out_shardings=self.rep)(seed_key(seed))
-
-    def loss_and_grads(self, params, batch, rows: slice = slice(None),
-                       den=None):
-        """Mean NLL over the selected positions of ``batch[rows]`` and its
-        gradient.  ``den`` overrides the count it is divided by."""
-        batch = {k: np.asarray(v)[rows] for k, v in batch.items()}
-        n_rows = batch["tokens"].shape[0]
-        step = min(self.block_rows, n_rows)
-        total, count, grads = 0.0, 0.0, None
-        for lo in range(0, n_rows, step):
-            blk = {k: jax.device_put(v[lo:lo + step], self.rows)
-                   for k, v in batch.items()}
-            s, n, g = self._grad_block(params, blk)
-            total += float(s)
-            count += float(n)
-            grads = g if grads is None else self._add(grads, g)
-        den = count if den is None else den
-        return total / den, self._scale(grads, 1.0 / den), count
-
-
-def lr_at(o, step: int) -> float:
-    warm = min(1.0, (step + 1) / max(1, o["warmup_steps"]))
-    prog = min(1.0, max(0.0, (step - o["warmup_steps"])
-                        / max(1, o["total_steps"] - o["warmup_steps"])))
-    cos = o["min_lr_ratio"] + (1 - o["min_lr_ratio"]) * 0.5 * (
-        1 + math.cos(math.pi * prog))
-    return o["lr"] * warm * cos
-
-
-def _adamw(o, step, params, grads, mu, nu):
-    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                         for g in jax.tree_util.tree_leaves(grads)))
-    scale = jnp.minimum(1.0, o["grad_clip"] / jnp.maximum(gnorm, 1e-9))
-    lr = lr_at(o, step)
-    t = step + 1
-    bc1, bc2 = 1 - o["b1"] ** t, 1 - o["b2"] ** t
-
-    def one(p, g, m, v):
-        g = g * scale
-        m = o["b1"] * m + (1 - o["b1"]) * g
-        v = o["b2"] * v + (1 - o["b2"]) * g * g
-        u = (m / bc1) / (jnp.sqrt(v / bc2) + o["eps"])
-        if p.ndim >= o["decay_min_ndim"]:
-            u = u + o["weight_decay"] * p
-        return p - lr * u, m, v
-
-    out = jax.tree_util.tree_map(one, params, grads, mu, nu)
-    pick = lambda i: jax.tree_util.tree_map(
-        lambda _, t: t[i], params, out)
-    return pick(0), pick(1), pick(2)
-
-
-@jax.jit
-def _norms(tree):
-    return [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-            for x in jax.tree_util.tree_leaves(tree)]
-
-
-def leaf_norms(tree) -> List[float]:
-    """The 2-norm of every leaf, in tree order."""
-    return [float(x) for x in _norms(tree)]
-
-
-def follow(c, seed: int, batches: Sequence[Dict[str, np.ndarray]], *,
-           ref: Reference, rows: slice = slice(None), global_den=False
-           ) -> Dict[str, Any]:
-    """Run the reference through ``len(batches)`` AdamW steps from the
-    weights of ``seed``.  Returns each step's loss, the norm of every leaf
-    of the first gradient as the optimizer gets it (before clipping), and
-    the norm of every leaf's change over all the steps.
-
-    ``rows`` restricts each step to those rows of its batch; with
-    ``global_den`` the loss is still divided by the whole batch's count
-    (what one replica computes when the exchange of gradients is left
-    out)."""
-    o = c["optimizer"]
-    p0 = ref.params(seed)
-    params = p0
-    zeros = jax.jit(lambda t: jax.tree_util.tree_map(jnp.zeros_like, t),
-                    out_shardings=ref.rep)
-    mu, nu = zeros(p0), zeros(p0)
-    step = jax.jit(lambda s, p, g, m, v: _adamw(o, s, p, g, m, v),
-                   static_argnums=0, donate_argnums=(2, 3, 4))
-    losses, first = [], None
-    for i, batch in enumerate(batches):
-        den = float(np.sum(batch["loss_mask"])) if global_den else None
-        loss, grads, _ = ref.loss_and_grads(params, batch, rows, den)
-        losses.append(loss)
-        if i == 0:
-            first = leaf_norms(grads)
-        new, mu, nu = step(i, params, grads, mu, nu)
-        if params is not p0:
-            del params
-        params = new
-    change = leaf_norms(jax.jit(lambda a, b: jax.tree_util.tree_map(
-        jnp.subtract, a, b))(params, p0))
-    return {"losses": losses, "grad_norms": first, "change_norms": change}
+    base = get_config(c["program_arch"])
+    d, h = c["hidden_size"], c["num_attention_heads"]
+    return dataclasses.replace(
+        base, d_model=d, n_heads=h, n_kv_heads=h, head_dim=d // h,
+        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
+        schedule=uniform_schedule(c["num_hidden_layers"], LayerSpec(ATTN)),
+        max_position=c["max_position_embeddings"],
+        norm_eps=c["layer_norm_eps"])

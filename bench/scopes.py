@@ -12,8 +12,9 @@ scopes it was traced under, with JAX's transformations around them:
 ``.../rematted_computation/...`` on what the backward pass recomputes.
 
 An operation's phase is the first of :data:`PHASES` whose mark its
-scope holds, so the phases are disjoint.  The parts are not phases: an
-``attention`` operation counts in its pass too.
+scope holds, so the phases are disjoint.  A part is any named scope
+(:data:`PARTS` lists those of the transformer layers and the loss head);
+parts are not phases: an ``attention`` operation counts in its pass too.
 """
 from __future__ import annotations
 
@@ -60,6 +61,7 @@ def phase_ms(ctx: Context, phase: str) -> Optional[float]:
 
 
 def part_ms(ctx: Context, part: str) -> Optional[float]:
-    """Device time a step of the operations under the ``part`` scope, in
-    any phase, mean over the chips; None where none is."""
-    return _per_step_ms(ctx, lambda o: PARTS[part](o.scope) is not None)
+    """Device time a step of the operations under the scope named
+    ``part``, in any phase, mean over the chips; None where none is."""
+    has = _component(part)
+    return _per_step_ms(ctx, lambda o: has(o.scope) is not None)

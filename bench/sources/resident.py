@@ -11,7 +11,8 @@ import itertools
 
 import jax
 
-from bench.reference.bert_mlm import mask_tokens, seed_key
+from bench.reference.bert_mlm import mask_tokens
+from bench.reference.core import seed_key
 
 RING_KEY = 1_000_003  # folded into the seed's key: apart from the weights'
 

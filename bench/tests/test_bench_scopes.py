@@ -127,3 +127,24 @@ def test_recorded_step_phases_cover_its_busy_time(recorded):
     assert min(phases, key=phases.get) == "optimizer"
     parts = [scopes.part_ms(recorded, p) for p in scopes.PARTS]
     assert all(parts) and sum(parts) <= busy
+
+
+# what the three parts read on the recorded step (ms) while part_ms took
+# only the names in PARTS: reading any scope by name moves none of them
+RECORDED_PARTS = {"attention": 504.48695899999996, "ffn": 266.519797,
+                  "loss_head": 93.73465999999999}
+
+
+@pytest.mark.parametrize("part", sorted(RECORDED_PARTS))
+def test_recorded_parts_read_as_before(recorded, part):
+    assert scopes.part_ms(recorded, part) == RECORDED_PARTS[part]
+
+
+@pytest.mark.parametrize("scope, reads", [
+    # a scope outside PARTS is read as a part: the whole component only
+    ("checkpoint", True), ("jit(log_softmax)", True),
+    ("check", False),
+    # a scope the step does not have reads nothing, and raises nothing
+    ("router", False)])
+def test_any_scope_is_a_part(recorded, scope, reads):
+    assert (scopes.part_ms(recorded, scope) is not None) is reads

@@ -29,8 +29,9 @@ SPANS = [("data_wait", "data", 0, 12), ("dispatch", "compute", 12, 14),
 
 @pytest.fixture
 def ctx():
-    c = {"num_hidden_layers": 1, "hidden_size": 8, "num_attention_heads": 2,
-         "intermediate_size": 16, "vocab_size": 32, "seq_len": 4}
+    c = {"reference": "bert_mlm", "num_hidden_layers": 1, "hidden_size": 8,
+         "num_attention_heads": 2, "intermediate_size": 16,
+         "vocab_size": 32, "seq_len": 4}
     peak = {"bf16_flops": 1e9, "hbm_bytes_per_s": 1e9}
     return tr.Context(config=c, batch=2, chips=1, steps=2, peak=peak,
                       trace=tr.Trace((0, 100), {"/device:TPU:0": OPS},
